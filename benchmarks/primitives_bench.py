@@ -96,6 +96,7 @@ def run() -> None:
         return
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"
     env[_CHILD] = "1"
     env.setdefault("PYTHONPATH", "src")
     out = subprocess.run(

@@ -117,6 +117,7 @@ def run() -> None:
                 record(row["name"], row["us_per_call"], row["derived"])
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"
     env[_CHILD] = "1"
     env.setdefault("PYTHONPATH", "src")
     report_path = os.environ.get("REPRO_SERVE_REPORT", "serve-latency.json")

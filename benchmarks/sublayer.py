@@ -212,6 +212,7 @@ def run() -> None:
     # dump_rows_json() ($REPRO_BENCH_JSON) covers the measured cells too.
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"
     env[_CHILD] = "1"
     env.setdefault("PYTHONPATH", "src")
     with tempfile.TemporaryDirectory() as td:

@@ -10,7 +10,6 @@ interleaved with partial dots (the fine-grained overlap).
 (re-executes itself with XLA_FLAGS for 8 virtual devices)
 """
 import os
-import re
 import subprocess
 import sys
 
@@ -25,6 +24,7 @@ def child():
     from repro import sharding
     from repro.core import dataflow as df
     from repro.core.primitives import CAISConfig
+    from repro.roofline.hlo_costs import collective_counts
 
     g = df.sublayer_graph()
     opt = df.optimize(g)
@@ -58,9 +58,9 @@ def child():
         out = fn(x, w["w1"], w["scale"], w["w2"])[0]
         err = float(jnp.abs(out - ref).max())
         hlo = fn.lower(x, w["w1"], w["scale"], w["w2"]).compile().as_text()
-        census = {k: len(re.findall(rf"= \S+ {k}\(", hlo))
-                  for k in ("all-gather", "reduce-scatter",
-                            "collective-permute")}
+        counts = collective_counts(hlo)
+        census = {k: counts[k] for k in ("all-gather", "reduce-scatter",
+                                         "collective-permute")}
         print(f"{name:12s} maxerr={err:.2e} hlo={census}")
 
 
@@ -70,6 +70,7 @@ def main():
         return
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"
     env[_CHILD] = "1"
     env.setdefault("PYTHONPATH", "src")
     code = ("import examples.cais_sublayer as m; m.child()"

@@ -104,7 +104,7 @@ class CollectiveBackend:
         the sequence cannot scatter over the ring (S % n != 0, e.g. S=1)."""
         if self.hierarchical(axis):
             return self.hier_gemm_ar(x, w, axis, cais)
-        n = prim._axis_size(axis) if cais.interpret_n is None \
+        n = jax.lax.axis_size(axis) if cais.interpret_n is None \
             else cais.interpret_n
         if int(x.shape[1]) % max(n, 1) != 0:
             return prim.barrier_gemm_ar(x, w, axis)
@@ -185,7 +185,7 @@ class CollectiveBackend:
         if self.hierarchical(axis):
             return self.hier_grad_a2a_expert_ffn(send, gy, bwd_row, axis,
                                                  cais)
-        n = prim._axis_size(axis) if cais.interpret_n is None \
+        n = jax.lax.axis_size(axis) if cais.interpret_n is None \
             else cais.interpret_n
         if n == 1:
             d_rows, dw_rows = jax.vmap(bwd_row)(send, gy)
@@ -257,7 +257,7 @@ class CollectiveBackend:
         ring fall back to the monolithic allreduce — ``lax.psum`` takes the
         axis tuple directly."""
         axes = tuple(axis)
-        if int(x.shape[1]) % max(prim._axis_size(axes), 1) != 0:
+        if int(x.shape[1]) % max(jax.lax.axis_size(axes), 1) != 0:
             return prim.barrier_gemm_ar(x, w, axes)
         y = self.hier_gemm_rs(x, w, axis, cais)
         y = self._outer_all_gather(y, axis[-1], cais)
@@ -379,7 +379,7 @@ class CAISBackend(CollectiveBackend):
         return _planned_chunks(int(payload_bytes), ring, bidirectional, hw)
 
     def _ring(self, axis, cais: CAISConfig) -> int:
-        return cais.interpret_n or prim._axis_size(axis)
+        return cais.interpret_n or jax.lax.axis_size(axis)
 
     def _resolve(self, cais: CAISConfig, gathered_bytes: float,
                  ring: int, inter: bool = False) -> CAISConfig:
@@ -405,14 +405,14 @@ class CAISBackend(CollectiveBackend):
         return prim.ring_all_gather(x, axis, cais)
 
     def _outer_all_gather(self, x, axis, cais):
-        ring = prim._axis_size(axis)
+        ring = jax.lax.axis_size(axis)
         if ring <= 1:
             return x
         cais = self._resolve(cais, self._nbytes(x) * ring, ring, inter=True)
         return prim.ring_all_gather(x, axis, cais)
 
     def _outer_reduce_scatter(self, x, axis, cais):
-        ring = prim._axis_size(axis)
+        ring = jax.lax.axis_size(axis)
         if ring <= 1:
             return x
         if int(x.shape[1]) % ring != 0:

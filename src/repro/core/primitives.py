@@ -69,11 +69,6 @@ def _ring_perms(n: int, direction: int) -> Sequence[Tuple[int, int]]:
     return [(i, (i - 1) % n) for i in range(n)]
 
 
-def _axis_size(axis: str) -> int:
-    from repro.sharding import shard_map_axis_size
-    return shard_map_axis_size(axis)
-
-
 # ---------------------------------------------------------------------------
 # Barrier (NVLS-style) baselines — one opaque collective around the GEMM
 # ---------------------------------------------------------------------------
@@ -115,7 +110,7 @@ def ag_gemm_multi(x: jnp.ndarray, ws: Sequence[jnp.ndarray], axis: str,
     column-sharded weights. Returns one (B, S_loc*n, F_k_loc) per weight —
     identical to ``barrier_ag_gemm`` per weight.
     """
-    n = cais.interpret_n or _axis_size(axis)
+    n = cais.interpret_n or lax.axis_size(axis)
     if n == 1:
         return tuple(x @ w for w in ws)
     B, S_loc, d = x.shape
@@ -198,7 +193,7 @@ def gemm_rs(x: jnp.ndarray, w: jnp.ndarray, axis: str,
     computed just-in-time as the rotating accumulator arrives (reduction "in
     flight": the ring hop is the merge unit).
     """
-    n = cais.interpret_n or _axis_size(axis)
+    n = cais.interpret_n or lax.axis_size(axis)
     if n == 1:
         return x @ w
     B, S, d_loc = x.shape
@@ -254,7 +249,7 @@ def gemm_ar(x: jnp.ndarray, w: jnp.ndarray, axis: str,
     """Basic-TP GEMM→AllReduce as RS + AG (both decomposed).
 
     x: (B, S, d_loc); w: (d_loc, F). Returns (B, S, F) fully reduced."""
-    n = cais.interpret_n or _axis_size(axis)
+    n = cais.interpret_n or lax.axis_size(axis)
     if n == 1:
         return x @ w
     y_loc = gemm_rs(x, w, axis, cais)       # (B, S_loc, F)
@@ -264,7 +259,7 @@ def gemm_ar(x: jnp.ndarray, w: jnp.ndarray, axis: str,
 def ring_all_gather(x: jnp.ndarray, axis: str,
                     cais: CAISConfig = CAISConfig()) -> jnp.ndarray:
     """Decomposed (bidirectional) ring all-gather along dim 1."""
-    n = cais.interpret_n or _axis_size(axis)
+    n = cais.interpret_n or lax.axis_size(axis)
     if n == 1:
         return x
     i = lax.axis_index(axis)
@@ -305,7 +300,7 @@ def ring_reduce_scatter(x: jnp.ndarray, axis: str,
     standalone counterpart of :func:`gemm_rs`'s rotating accumulator, used
     as the outer-tier (inter-node) leg of hierarchical compositions where
     the GEMM already happened on the inner ring."""
-    n = cais.interpret_n or _axis_size(axis)
+    n = cais.interpret_n or lax.axis_size(axis)
     if n == 1:
         return x
     S = x.shape[1]
@@ -377,7 +372,7 @@ def a2a_expert_ffn(send: jnp.ndarray, ffn: Callable, axis: str,
     are single HLO ops that a torus lowers to ≤o hops; the dry-run's
     byte accounting counts payload once per permute (same as a2a's slices).
     """
-    n = cais.interpret_n or _axis_size(axis)
+    n = cais.interpret_n or lax.axis_size(axis)
     if n == 1:
         return jax.vmap(ffn)(send)
     i = lax.axis_index(axis)
@@ -434,7 +429,7 @@ def grad_a2a_expert_ffn(send: jnp.ndarray, gy: jnp.ndarray,
     LOCALLY at the owner — they never ride a collective. Returns
     ``(d_send, dw_tuple)`` with ``d_send`` shaped like ``send``.
     """
-    n = cais.interpret_n or _axis_size(axis)
+    n = cais.interpret_n or lax.axis_size(axis)
     if n == 1:
         d_rows, dw_rows = jax.vmap(bwd_row)(send, gy)
         return d_rows, tuple(jnp.sum(a, axis=0) for a in dw_rows)
@@ -516,7 +511,7 @@ def overlap_asymmetric(rs_args, ag_args, axis: str,
     x_ag, w_ag = ag_args
     multi = isinstance(w_ag, (tuple, list))
     ws_ag = tuple(w_ag) if multi else (w_ag,)
-    n = cais.interpret_n or _axis_size(axis)
+    n = cais.interpret_n or lax.axis_size(axis)
     if n == 1:
         outs = tuple(x_ag @ w for w in ws_ag)
         return x_rs @ w_rs, (outs if multi else outs[0])

@@ -10,6 +10,7 @@ from dataclasses import dataclass
 
 @dataclass(frozen=True)
 class HWSpec:
+    device_kind: str = "TPU v5 lite"  # jax.devices()[0].device_kind of a v5e
     peak_flops: float = 197e12        # bf16 FLOP/s per chip
     hbm_bw: float = 819e9             # bytes/s per chip
     ici_bw: float = 50e9              # bytes/s per link per direction

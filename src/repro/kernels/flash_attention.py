@@ -20,6 +20,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.matmul import SUBLANE, block_divisor
+
 NEG_INF = -1e30
 
 
@@ -70,7 +72,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                     causal: bool = True, bq: int = 256, bkv: int = 256,
-                    scale=None, interpret: bool = True):
+                    scale=None, interpret: bool = False):
     """q, k, v: (BH, S, d) — batch and heads pre-folded. Returns (BH, S, d).
 
     VMEM working set per step: q(bq·d) + k,v(bkv·d) + acc(bq·d f32)
@@ -78,9 +80,8 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     BH, Sq, d = q.shape
     _, Skv, _ = k.shape
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
-    from repro.kernels.matmul import block_divisor
-    bq = block_divisor(Sq, bq)
-    bkv = block_divisor(Skv, bkv)
+    bq = block_divisor(Sq, bq, SUBLANE, shape=q.shape)
+    bkv = block_divisor(Skv, bkv, SUBLANE, shape=k.shape)
     n_kv = Skv // bkv
 
     return pl.pallas_call(

@@ -32,25 +32,42 @@ def _matmul_kernel(a_ref, b_ref, o_ref, acc_ref, *, n_k: int):
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
-def block_divisor(dim: int, want: int) -> int:
-    b = max(1, min(dim, want))
-    while dim % b:
-        b //= 2
-    return max(b, 1)
+# Mosaic's tiling rule: the last two dims of a block are multiples of
+# (8, 128) unless they span the whole array dim.
+SUBLANE, LANE = 8, 128
+
+
+def block_divisor(dim: int, want: int, align: int, *, shape=None) -> int:
+    """Block length along one array dim of extent ``dim``: the whole dim
+    when it fits in ``max(want, align)``, else the largest multiple of
+    ``align`` that divides ``dim`` and is at most ``max(want, align)``.
+    Raises ``ValueError`` naming ``shape`` when no such block exists."""
+    cap = max(want, align)
+    if dim <= cap:
+        return dim
+    for b in range(cap - cap % align, 0, -align):
+        if dim % b == 0:
+            return b
+    raise ValueError(
+        f"no block of at most {cap} that is a multiple of {align} divides "
+        f"dim {dim} of shape {tuple(shape) if shape else (dim,)}; pad the "
+        f"array to a multiple of {align}")
 
 
 def matmul(a: jnp.ndarray, b: jnp.ndarray, *, bm: int = 256, bn: int = 256,
-           bk: int = 512, interpret: bool = True, out_dtype=None):
-    """a: (M, K) @ b: (K, N) -> (M, N). Block sizes are clipped to divisors
-    of the problem shape; defaults keep the VMEM working set
+           bk: int = 512, interpret: bool = False, out_dtype=None):
+    """a: (M, K) @ b: (K, N) -> (M, N). Block sizes are clipped to
+    (8, 128)-aligned divisors of the problem shape (``block_divisor``);
+    defaults keep the VMEM working set
     (bm·bk + bk·bn tiles bf16 + bm·bn f32 accumulator ≈ 0.5 MB) well under
     the ~128 MB/core budget while filling the MXU (≥128 in every dim)."""
     M, K = a.shape
     K2, N = b.shape
     assert K == K2, (a.shape, b.shape)
     out_dtype = out_dtype or a.dtype
-    bm, bn, bk = (block_divisor(M, bm), block_divisor(N, bn),
-                  block_divisor(K, bk))
+    bm = block_divisor(M, bm, SUBLANE, shape=a.shape)
+    bk = block_divisor(K, bk, LANE, shape=a.shape)
+    bn = block_divisor(N, bn, LANE, shape=b.shape)
     n_k = K // bk
 
     return pl.pallas_call(

@@ -18,7 +18,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.matmul import block_divisor
+from repro.kernels.matmul import LANE, SUBLANE, block_divisor
 
 
 def _matmul_ln_kernel(a_ref, b_ref, scale_ref, o_ref, acc_ref, *,
@@ -43,13 +43,14 @@ def _matmul_ln_kernel(a_ref, b_ref, scale_ref, o_ref, acc_ref, *,
 
 def matmul_rmsnorm(a: jnp.ndarray, b: jnp.ndarray, scale: jnp.ndarray, *,
                    bm: int = 128, bk: int = 512, eps: float = 1e-6,
-                   interpret: bool = True, out_dtype=None):
+                   interpret: bool = False, out_dtype=None):
     """rmsnorm(a @ b) * (1 + scale). a: (M, K); b: (K, N); scale: (N,)."""
     M, K = a.shape
     K2, N = b.shape
     assert K == K2 and scale.shape == (N,)
     out_dtype = out_dtype or a.dtype
-    bm, bk = block_divisor(M, bm), block_divisor(K, bk)
+    bm = block_divisor(M, bm, SUBLANE, shape=a.shape)
+    bk = block_divisor(K, bk, LANE, shape=a.shape)
     n_k = K // bk
 
     return pl.pallas_call(

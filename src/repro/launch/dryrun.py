@@ -1,9 +1,12 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 # ^ MUST precede every other import (jax locks the device count on first
 # init). 512 virtual host devices host the production meshes: 16×16 single
 # pod and 2×16×16 multi-pod. This module is the ONLY place that sets it.
+# The dry-run compiles on the CPU backend, so it never opens (or waits
+# for) an accelerator that the host may have.
 
 import argparse          # noqa: E402
 import json              # noqa: E402

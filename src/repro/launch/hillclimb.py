@@ -16,6 +16,7 @@ against the measured roofline bounds (docs/planner.md)."""
 import os
 os.environ.setdefault("XLA_FLAGS",
                       "--xla_force_host_platform_device_count=512")
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 import argparse  # noqa: E402
 import json      # noqa: E402

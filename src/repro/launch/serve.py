@@ -1,10 +1,14 @@
-"""Production serving launcher: load-generated requests through the paged
+"""Serving launcher: load-generated requests through the paged
 continuous-batching Engine (docs/serving.md).
 
     python -m repro.launch.serve --smoke
     python -m repro.launch.serve --arch gemma3-1b --load poisson --rate 16
     python -m repro.launch.serve --arch deepseek-7b --engine dense \
         --load burst --report reports/serve_latency.json
+
+``--mesh host`` spreads tensor parallelism over every device present.
+``main(argv)`` takes the argument list and returns ``(engine, requests)``,
+so a script can drive the same code in its own process.
 
 ``--load none`` keeps the old fixed-prompt batch; ``poisson``/``burst``
 drive the seeded arrival processes from :mod:`repro.serve.loadgen` and
@@ -17,23 +21,24 @@ import argparse
 import dataclasses
 import json
 import os
+from typing import List, Optional
 
 import jax
 import numpy as np
 
 from repro.configs import get_arch
 from repro.launch import specs as S
-from repro.launch.mesh import make_debug_mesh, make_production_mesh
+from repro.launch.cache import init_compile_cache
+from repro.launch.mesh import MESHES
 from repro.models import build_model
 from repro.serve import (DenseEngine, Engine, LoadSpec, Request, ServeConfig,
                          format_report, generate)
 
 
-def main() -> None:
+def main(argv: Optional[List[str]] = None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma3-1b")
-    ap.add_argument("--mesh", default="none",
-                    choices=["none", "debug", "single", "multi"])
+    ap.add_argument("--mesh", default="none", choices=sorted(MESHES))
     ap.add_argument("--engine", default="paged", choices=["paged", "dense"])
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--prompt-len", type=int, default=16)
@@ -45,7 +50,8 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--report", default="")
     ap.add_argument("--smoke", action="store_true")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    init_compile_cache()
 
     cfg = get_arch(args.arch)
     rt = S.runtime_for(cfg)
@@ -56,10 +62,7 @@ def main() -> None:
         args.requests = min(args.requests, 4)
         args.prompt_len = min(args.prompt_len, 8)
         args.max_new = min(args.max_new, 4)
-    mesh = {"none": None, "debug": make_debug_mesh,
-            "single": lambda: make_production_mesh(multi_pod=False),
-            "multi": lambda: make_production_mesh(multi_pod=True)}[args.mesh]
-    mesh = mesh() if callable(mesh) else mesh
+    mesh = MESHES[args.mesh]()
 
     model = build_model(cfg, rt)
     params = model.init(jax.random.key(0))
@@ -110,6 +113,7 @@ def main() -> None:
         print("serve smoke OK "
               f"(arch={args.arch} engine={args.engine} paged="
               f"{getattr(eng, '_paged', False)})")
+    return eng, reqs
 
 
 if __name__ == "__main__":

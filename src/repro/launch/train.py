@@ -1,40 +1,76 @@
-"""Production training launcher.
+"""Training launcher.
 
-    python -m repro.launch.train --arch deepseek-7b --shape train_4k \
-        --mesh single --tp-mode cais --steps 100 --ckpt-dir /ckpts/run1
+    python -m repro.launch.train --arch gemma3-1b --periods 1 --batch 1 \
+        --steps 3
+    python -m repro.launch.train --arch deepseek-7b --mesh host --periods 2 \
+        --batch 4 --tp-mode cais --steps 3 --ckpt-dir /ckpts/run1
+    python -m repro.launch.train --arch deepseek-7b --smoke --mesh debug
 
-On a real pod this process runs per-host under the TPU runtime and the mesh
-maps onto physical chips; on this box it drives whatever devices exist (use
---smoke for a reduced config on CPU). All state is sharded per
-launch/specs.py; restart is automatic from --ckpt-dir (deterministic resume,
-see train/trainer.py)."""
+Widths are always the published ones; ``--periods`` cuts depth in whole
+``layer_pattern`` periods and ``--batch`` sets the sequences per
+data-parallel replica, so one chip or one four-chip host can run the step.
+``--mesh host`` spreads tensor parallelism over every device present;
+``--smoke`` is the reduced-width CPU configuration the tests use. All state
+is sharded per launch/specs.py; restart is automatic from --ckpt-dir
+(deterministic resume, see train/trainer.py).
+
+``main(argv)`` and ``build(argv)`` take the argument list, so a script can
+drive the same code in its own process."""
 from __future__ import annotations
 
 import argparse
 import dataclasses
+from typing import List, Optional, Tuple
 
 import jax
 
 from repro import sharding
-from repro.configs import SHAPES_BY_NAME, ShapeConfig, get_arch
+from repro.configs import SHAPES_BY_NAME, ArchConfig, ShapeConfig, get_arch
 from repro.core.backends import available_backends
 from repro.data.pipeline import DataConfig
 from repro.launch import specs as S
-from repro.launch.mesh import make_debug_mesh, make_production_mesh
+from repro.launch.cache import init_compile_cache
+from repro.launch.mesh import MESHES
 from repro.models import build_model
 from repro.optim import cosine_schedule, make_optimizer
 from repro.train import Trainer, TrainerConfig
-from repro.train.step import init_state
 
 
-def main() -> None:
+def cut_to_size(cfg: ArchConfig, shape: ShapeConfig, *, periods: int,
+                batch: int, dp: int
+                ) -> Tuple[ArchConfig, ShapeConfig, List[str]]:
+    """Cut depth to ``periods`` whole ``layer_pattern`` periods and the
+    global batch to ``batch`` sequences per data-parallel replica (``dp``
+    replicas). 0 keeps the published value. Returns the cut config and
+    shape and one line per cut made."""
+    cuts = []
+    if periods:
+        layers = periods * len(cfg.layer_pattern)
+        if layers > cfg.num_layers:
+            raise ValueError(f"--periods {periods} gives {layers} layers; "
+                             f"{cfg.name} has {cfg.num_layers}")
+        cuts.append(f"num_layers {cfg.num_layers} -> {layers} ({periods} "
+                    f"period(s) of {len(cfg.layer_pattern)})")
+        cfg = dataclasses.replace(cfg, num_layers=layers)
+    if batch:
+        cuts.append(f"global_batch {shape.global_batch} -> {batch * dp} "
+                    f"({batch} per replica x {dp} replica(s))")
+        shape = dataclasses.replace(shape, global_batch=batch * dp)
+    return cfg, shape, cuts
+
+
+def parse_args(argv: Optional[List[str]] = None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--shape", default="train_4k")
-    ap.add_argument("--mesh", default="none",
-                    choices=["none", "debug", "single", "multi"])
+    ap.add_argument("--mesh", default="none", choices=sorted(MESHES))
     ap.add_argument("--tp-mode", default="auto",
                     choices=available_backends())
+    ap.add_argument("--periods", type=int, default=0,
+                    help="depth cut: whole layer_pattern periods (0: all)")
+    ap.add_argument("--batch", type=int, default=0,
+                    help="sequences per data-parallel replica (0: the "
+                         "shape's global batch)")
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--warmup", type=int, default=20)
@@ -44,8 +80,15 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--smoke", action="store_true",
                     help="reduced config + tiny shape (CPU-runnable)")
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
+
+def build(argv: Optional[List[str]] = None):
+    """The trainer the launcher runs and its initial state (restored from
+    ``--ckpt-dir`` or fresh from ``--seed``), already placed on the mesh:
+    ``(trainer, state)``."""
+    args = parse_args(argv)
+    init_compile_cache()
     cfg = get_arch(args.arch)
     if args.smoke:
         cfg = cfg.smoke()
@@ -57,10 +100,12 @@ def main() -> None:
         shape = SHAPES_BY_NAME[args.shape]
         rt = S.runtime_for(cfg, tp_mode=args.tp_mode)
 
-    mesh = {"none": None, "debug": make_debug_mesh,
-            "single": lambda: make_production_mesh(multi_pod=False),
-            "multi": lambda: make_production_mesh(multi_pod=True)}[args.mesh]
-    mesh = mesh() if callable(mesh) else mesh
+    mesh = MESHES[args.mesh]()
+    cfg, shape, cuts = cut_to_size(cfg, shape, periods=args.periods,
+                                   batch=args.batch,
+                                   dp=sharding.dp_size(mesh))
+    for c in cuts:
+        print(f"cut: {c}")
 
     model = build_model(cfg, rt)
     opt = make_optimizer(cfg.optimizer,
@@ -70,17 +115,18 @@ def main() -> None:
                        microbatches=args.microbatches)
     trainer = Trainer(model, opt, cfg, shape, rt, tc, DataConfig(args.seed),
                       mesh=mesh)
+    if mesh is None:
+        return trainer, trainer.restore_or_init(args.seed)
+    with sharding.use_mesh(mesh):
+        shapes = jax.eval_shape(trainer.fresh_state, args.seed)
+        sh = S.state_shardings(cfg, mesh, shapes, rt)
+        return trainer, trainer.restore_or_init(args.seed, sh)
 
-    if mesh is not None:
-        # shard the fresh/restored state onto the mesh before stepping
-        with sharding.use_mesh(mesh):
-            state = trainer.restore_or_init(args.seed)
-            shapes = jax.eval_shape(lambda: state)
-            sh = S.state_shardings(cfg, mesh, shapes, rt)
-            state = jax.device_put(state, sh)
-            trainer.run(state)
-    else:
-        trainer.run()
+
+def main(argv: Optional[List[str]] = None):
+    """Build and run; returns ``(trainer, final state)``."""
+    trainer, state = build(argv)
+    return trainer, trainer.run(state)
 
 
 if __name__ == "__main__":

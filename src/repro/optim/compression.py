@@ -42,11 +42,7 @@ def compressed_psum(grads, error_feedback, axes: Sequence[str],
 
     Each tensor: x = g + ef; q = int8(x); wire = psum(q int32) (+ scales via
     f32 psum — negligible bytes); ef' = x − deq(q). Returns (reduced, ef')."""
-    from repro.sharding import shard_map_axis_size
-
-    n = 1
-    for a in axes:
-        n *= shard_map_axis_size(a)
+    n = jax.lax.axis_size(tuple(axes))
 
     def one(g, ef):
         x = g.astype(jnp.float32) + ef

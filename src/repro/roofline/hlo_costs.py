@@ -2,6 +2,8 @@
 
 * ``cost_summary(compiled)``   — FLOPs / bytes-accessed from cost_analysis()
   (per-device numbers: XLA analyzes the partitioned per-device module).
+* ``collective_counts(hlo)``   — collective ops of each kind, as the TPU
+  compiler emits them (sync, async ``-start``, fused).
 * ``collective_bytes(hlo)``    — per-device wire bytes, parsed from the HLO
   text: for every all-gather / all-reduce / reduce-scatter / all-to-all /
   collective-permute op, sum the operand sizes (cost_analysis does not
@@ -91,10 +93,24 @@ def collective_bytes(hlo_text: str) -> Dict[str, int]:
     return per_op
 
 
+# Fused collectives the TPU compiler emits as a fusion that calls a
+# computation of this name, by the collective they perform.
+_FUSED_COLLECTIVES = {"all-reduce-scatter": "reduce-scatter"}
+
+
+def collective_counts(hlo_text: str) -> Dict[str, int]:
+    """Number of collective ops of each kind in a compiled module's text:
+    sync ops, the ``-start`` half of async pairs, and the TPU's fused
+    collectives (``fusion(...), calls=%all-reduce-scatter``)."""
+    counts = {k: len(re.findall(rf"(?<![\w\-]){k}(?:-start)?\(", hlo_text))
+              for k in COLLECTIVE_OPS}
+    for name, kind in _FUSED_COLLECTIVES.items():
+        counts[kind] += len(re.findall(rf"calls=%{name}\b", hlo_text))
+    return counts
+
+
 def cost_summary(compiled) -> Dict[str, float]:
     ca = compiled.cost_analysis()
-    if isinstance(ca, list):
-        ca = ca[0]
     return {
         "flops": float(ca.get("flops", 0.0)),
         "bytes": float(ca.get("bytes accessed", 0.0)),

@@ -33,14 +33,12 @@ TP_AXES_2D = (TP_IN_AXIS, TP_OUT_AXIS)
 
 
 def make_mesh(shape, axes) -> Mesh:
-    """Version-portable ``jax.make_mesh`` with Auto axis types when the
-    running jax supports them (older releases have neither ``AxisType`` nor
-    the ``axis_types`` parameter)."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis ``Auto``: the model code places
+    activations with sharding constraints and lets the compiler propagate
+    the rest, which ``jax.make_mesh``'s default explicit axes would
+    refuse."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_tp_mesh(tp_in: int, tp_out: int, dp: int = 1) -> Mesh:
@@ -62,21 +60,6 @@ def tp_axes(mesh: Optional[Mesh]):
     return MODEL_AXIS
 
 
-def shard_map_axis_size(axis) -> int:
-    """Size of a named mesh axis (or product over a composite-axis tuple)
-    from *inside* shard_map, version-portable: newer jax has
-    ``lax.axis_size``; older releases constant-fold ``psum(1, axis)`` to the
-    same value."""
-    if isinstance(axis, (tuple, list)):
-        n = 1
-        for a in axis:
-            n *= shard_map_axis_size(a)
-        return n
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis)
-    return jax.lax.psum(1, axis)
-
-
 def shard_map_axis_index(axis):
     """Flattened device index along ``axis`` from inside shard_map. For a
     composite tuple the first member is major (index = i0·n1·… + i1·… + …),
@@ -84,21 +67,13 @@ def shard_map_axis_index(axis):
     if isinstance(axis, (tuple, list)):
         idx = jax.lax.axis_index(axis[0])
         for a in axis[1:]:
-            idx = idx * shard_map_axis_size(a) + jax.lax.axis_index(a)
+            idx = idx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
         return idx
     return jax.lax.axis_index(axis)
 
 
-def shard_map(fn, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-portable ``shard_map``: newer jax exposes ``jax.shard_map``
-    (with ``check_vma``); older releases ship it under ``jax.experimental``
-    (where the flag is ``check_rep``). All repo code routes through here."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)
+# All repo code calls shard_map through this name.
+shard_map = jax.shard_map
 
 # Batch dims shard over every data-parallel axis present on the mesh.
 BATCH_AXES = (POD_AXIS, DATA_AXIS)

@@ -50,24 +50,33 @@ class Trainer:
     _clock: Callable[[], float] = time.monotonic
 
     def __post_init__(self):
+        # the step donates the state it replaces, so a device holds one
+        # state at a time (run() never reads a state after stepping it)
         self.train_step = jax.jit(
             make_train_step(self.model, self.opt, self.rt,
-                            self.cfg.microbatches))
+                            self.cfg.microbatches), donate_argnums=(0,))
         self.watchdog = StepWatchdog(threshold=self.cfg.straggler_threshold)
         self.history: List[Dict[str, float]] = []
         self.events: List[str] = []
 
     # ----- state -----
-    def fresh_state(self, seed: int = 0):
-        return init_state(self.model, self.opt, jax.random.key(seed))
+    def fresh_state(self, seed: int = 0, shardings=None):
+        """Initial state from ``seed``, built on the device. ``shardings``
+        (a pytree of shardings matching the state) places every array
+        where it belongs as it is made, so no device ever holds the whole
+        state of a sharded model."""
+        init = jax.jit(lambda key: init_state(self.model, self.opt, key),
+                       out_shardings=shardings)
+        return init(jax.random.key(seed))
 
-    def restore_or_init(self, seed: int = 0):
+    def restore_or_init(self, seed: int = 0, shardings=None):
         if self.cfg.ckpt_dir and store.latest_step(self.cfg.ckpt_dir) is not None:
             template = jax.eval_shape(self.fresh_state, seed)
             state, manifest = store.restore(self.cfg.ckpt_dir, template)
             self.events.append(f"restored step {manifest['step']}")
-            return state
-        return self.fresh_state(seed)
+            return state if shardings is None else \
+                jax.device_put(state, shardings)
+        return self.fresh_state(seed, shardings)
 
     # ----- loop -----
     def run(self, state=None, seed: int = 0):

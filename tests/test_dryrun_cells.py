@@ -20,7 +20,6 @@ CELLS = [
 ]
 
 
-@pytest.mark.slow
 @pytest.mark.parametrize("arch,shape,mesh", CELLS)
 def test_dryrun_cell(arch, shape, mesh, tmp_path):
     env = dict(os.environ)
@@ -29,7 +28,7 @@ def test_dryrun_cell(arch, shape, mesh, tmp_path):
     proc = subprocess.run(
         [sys.executable, "-m", "repro.launch.dryrun", "--arch", arch,
          "--shape", shape, "--mesh", mesh, "--out", str(tmp_path)],
-        capture_output=True, text=True, env=env, timeout=1800,
+        capture_output=True, text=True, env=env, timeout=300,
         cwd=str(REPO))
     sys.stdout.write(proc.stdout[-2000:])
     assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr[-2000:]

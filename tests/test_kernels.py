@@ -1,9 +1,13 @@
-"""Per-kernel shape/dtype sweeps: Pallas (interpret=True) vs pure-jnp oracle."""
+"""Per-kernel shape/dtype sweeps: Pallas (interpret=True) vs pure-jnp oracle.
+
+Every call passes ``interpret=True`` itself: the wrappers lower natively
+unless told otherwise."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.kernels import ops, ref
 
@@ -28,7 +32,7 @@ def _rand(key, shape, dtype):
 def test_matmul_sweep(M, K, N, dtype):
     a = _rand(jax.random.key(0), (M, K), dtype)
     b = _rand(jax.random.key(1), (K, N), dtype)
-    got = ops.matmul(a, b)
+    got = ops.matmul(a, b, interpret=True)
     want = ref.matmul_ref(a, b)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), **TOL[dtype])
@@ -39,7 +43,7 @@ def test_matmul_sweep(M, K, N, dtype):
 def test_matmul_block_shapes(bm, bn, bk):
     a = _rand(jax.random.key(2), (256, 512), jnp.float32)
     b = _rand(jax.random.key(3), (512, 128), jnp.float32)
-    got = ops.matmul(a, b, bm=bm, bn=bn, bk=bk)
+    got = ops.matmul(a, b, bm=bm, bn=bn, bk=bk, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref.matmul_ref(a, b)),
                                **TOL[jnp.float32])
 
@@ -50,7 +54,7 @@ def test_matmul_block_shapes(bm, bn, bk):
 def test_matmul_property(m, k, n):
     a = _rand(jax.random.key(m * k), (m, k), jnp.float32)
     b = _rand(jax.random.key(k * n + 1), (k, n), jnp.float32)
-    got = ops.matmul(a, b)
+    got = ops.matmul(a, b, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref.matmul_ref(a, b)),
                                **TOL[jnp.float32])
 
@@ -67,7 +71,7 @@ def test_matmul_rmsnorm_sweep(M, K, N, dtype):
     a = _rand(jax.random.key(0), (M, K), dtype)
     b = _rand(jax.random.key(1), (K, N), dtype)
     scale = _rand(jax.random.key(2), (N,), jnp.float32) * 0.1
-    got = ops.matmul_rmsnorm(a, b, scale)
+    got = ops.matmul_rmsnorm(a, b, scale, interpret=True)
     want = ref.matmul_rmsnorm_ref(a, b, scale)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), **TOL[dtype])
@@ -79,7 +83,7 @@ def test_matmul_rmsnorm_matches_model_norm():
     a = _rand(jax.random.key(0), (32, 64), jnp.float32)
     b = _rand(jax.random.key(1), (64, 48), jnp.float32)
     scale = _rand(jax.random.key(2), (48,), jnp.float32) * 0.1
-    got = ops.matmul_rmsnorm(a, b, scale)
+    got = ops.matmul_rmsnorm(a, b, scale, interpret=True)
     want = apply_norm("rmsnorm", {"scale": scale}, a @ b)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5,
                                atol=2e-4)
@@ -98,7 +102,8 @@ def test_flash_attention_sweep(BH, S, d, dtype, causal):
     q = _rand(ks[0], (BH, S, d), dtype)
     k = _rand(ks[1], (BH, S, d), dtype)
     v = _rand(ks[2], (BH, S, d), dtype)
-    got = ops.flash_attention(q, k, v, causal=causal, bq=64, bkv=64)
+    got = ops.flash_attention(q, k, v, causal=causal, bq=64, bkv=64,
+                              interpret=True)
     want = ref.flash_attention_ref(q, k, v, causal=causal)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), **TOL[dtype])
@@ -118,7 +123,8 @@ def test_flash_matches_model_attention_core():
     qf = q.transpose(0, 2, 1, 3).reshape(B * H, S, d)
     kf = k.transpose(0, 2, 1, 3).reshape(B * H, S, d)
     vf = v.transpose(0, 2, 1, 3).reshape(B * H, S, d)
-    got = ops.flash_attention(qf, kf, vf, causal=True, bq=32, bkv=32)
+    got = ops.flash_attention(qf, kf, vf, causal=True, bq=32, bkv=32,
+                              interpret=True)
     got = got.reshape(B, H, S, d).transpose(0, 2, 1, 3)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5,
                                atol=2e-4)
@@ -130,7 +136,29 @@ def test_flash_matches_model_attention_core():
 def test_flash_block_shape_property(s, d, bq, bkv):
     ks = jax.random.split(jax.random.key(s * d + bq), 3)
     q, k, v = (_rand(kk, (1, s, d), jnp.float32) for kk in ks)
-    got = ops.flash_attention(q, k, v, causal=True, bq=bq, bkv=bkv)
+    got = ops.flash_attention(q, k, v, causal=True, bq=bq, bkv=bkv,
+                              interpret=True)
     want = ref.flash_attention_ref(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5,
                                atol=2e-4)
+
+
+# ---------------------------------------------------------------------------
+# block sizing: (8, 128)-aligned divisors or the whole dim, else a clear error
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("dim,want,align,block", [
+    (4096, 256, 8, 256), (96, 256, 128, 96), (128, 32, 128, 128),
+    (320, 64, 8, 64), (384, 512, 128, 384), (1152, 512, 128, 384),
+    (6912, 512, 128, 384),
+])
+def test_block_divisor_aligned(dim, want, align, block):
+    from repro.kernels.matmul import block_divisor
+    assert block_divisor(dim, want, align) == block
+
+
+def test_block_divisor_rejects_unaligned_dim():
+    from repro.kernels.matmul import block_divisor
+    with pytest.raises(ValueError, match=r"dim 4100 of shape \(4100, 4096\)"):
+        block_divisor(4100, 256, 8, shape=(4100, 4096))

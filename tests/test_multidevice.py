@@ -18,13 +18,15 @@ def test_main_process_single_device():
 
 
 @pytest.mark.slow
-def test_multidevice_suite():
+@pytest.mark.parametrize("part", ["rings", "period", "split", "model"])
+def test_multidevice_suite(part):
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = str(REPO / "src")
     proc = subprocess.run(
-        [sys.executable, str(HERE / "multidev_checks.py")],
-        capture_output=True, text=True, env=env, timeout=2400)
+        [sys.executable, str(HERE / "multidev_checks.py"), part],
+        capture_output=True, text=True, env=env, timeout=1200)
     sys.stdout.write(proc.stdout)
     sys.stderr.write(proc.stderr[-4000:])
     assert proc.returncode == 0, "multi-device checks failed"

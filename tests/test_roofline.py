@@ -59,8 +59,7 @@ def test_raw_cost_analysis_undercounts_scans():
     x = jax.ShapeDtypeStruct((128, 256), jnp.float32)
     w = jax.ShapeDtypeStruct((256, 256), jnp.float32)
     compiled = jax.jit(f).lower(x, w).compile()
-    ca = compiled.cost_analysis()
-    raw = (ca[0] if isinstance(ca, list) else ca)["flops"]
+    raw = compiled.cost_analysis()["flops"]
     true = analyze(compiled.as_text())["flops"]
     assert true > 10 * raw  # 16 trips counted once
 

@@ -6,7 +6,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st  # noqa: F401
 
 from repro.checkpoint import store
 from repro.configs import SHAPES_BY_NAME, ShapeConfig, get_arch

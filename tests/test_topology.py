@@ -18,7 +18,8 @@ import sys
 
 import pytest
 
-from _hypothesis_compat import given, st
+from hypothesis import given
+from hypothesis import strategies as st
 from repro import sharding
 from repro.core import coordination
 from repro.hw import V5E
@@ -156,10 +157,11 @@ def test_composite_flat_index_layout():
 def test_topology_suite():
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = str(REPO / "src")
     proc = subprocess.run(
         [sys.executable, str(HERE / "topo_checks.py")],
-        capture_output=True, text=True, env=env, timeout=2400,
+        capture_output=True, text=True, env=env, timeout=1200,
         cwd=str(REPO))
     sys.stdout.write(proc.stdout)
     sys.stderr.write(proc.stderr[-4000:])
