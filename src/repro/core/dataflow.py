@@ -610,7 +610,11 @@ def execute(g: Graph, values: Dict[str, jnp.ndarray],
     compose per-tier here (inter-node gather first / intra-node scatter
     first, matching the tp_in-major shard order). Without ``axis``,
     collectives degenerate to identity/plain math (single-device
-    reference)."""
+    reference).
+
+    Each node runs under ``jax.named_scope("df.<op>.<node name>")``, so
+    every HLO instruction it emits names its node in ``op_name``
+    (``bench/spans.py`` reads device time per node kind from it)."""
     from repro.core.backends import get_backend
     from repro.models.layers import apply_norm
 
@@ -622,143 +626,146 @@ def execute(g: Graph, values: Dict[str, jnp.ndarray],
     for n in g.nodes:
         if n.op == "input":
             continue
-        ins = [env[v] for v in n.inputs]
-        ws = [weights[k] for k in n.weights]
-        if n.op == "gemm_col" or n.op == "gemm_row":
-            env[n.name] = ins[0] @ ws[0]
-        elif n.op == "allgather":
-            if dist and hier:
-                out = jax.lax.all_gather(ins[0], axis[-1], axis=1, tiled=True)
-                env[n.name] = jax.lax.all_gather(out, axis[0], axis=1,
-                                                 tiled=True)
-            else:
-                env[n.name] = (jax.lax.all_gather(ins[0], axis, axis=1,
-                                                  tiled=True)
-                               if dist else ins[0])
-        elif n.op == "reduce_scatter":
-            if dist and hier:
-                out = jax.lax.psum_scatter(ins[0], axis[0],
-                                           scatter_dimension=1, tiled=True)
-                env[n.name] = jax.lax.psum_scatter(out, axis[-1],
-                                                   scatter_dimension=1,
-                                                   tiled=True)
-            else:
-                env[n.name] = (jax.lax.psum_scatter(ins[0], axis,
-                                                    scatter_dimension=1,
-                                                    tiled=True)
-                               if dist else ins[0])
-        elif n.op == "allreduce":
-            env[n.name] = jax.lax.psum(ins[0], axis) if dist else ins[0]
-        elif n.op == "layernorm":
-            env[n.name] = apply_norm(norm, {"scale": ws[0]}, ins[0])
-        elif n.op in ("add", "residual"):
-            env[n.name] = ins[0] + ins[1]
-        elif n.op in _FN_OPS:
-            res = n.fn(*ins, *ws)
-            if len(n.outputs) > 1:
-                for name, val in zip(n.outputs, res):
+        with jax.named_scope(f"df.{n.op}.{n.name}"):
+            ins = [env[v] for v in n.inputs]
+            ws = [weights[k] for k in n.weights]
+            if n.op == "gemm_col" or n.op == "gemm_row":
+                env[n.name] = ins[0] @ ws[0]
+            elif n.op == "allgather":
+                if dist and hier:
+                    out = jax.lax.all_gather(ins[0], axis[-1], axis=1,
+                                             tiled=True)
+                    env[n.name] = jax.lax.all_gather(out, axis[0], axis=1,
+                                                     tiled=True)
+                else:
+                    env[n.name] = (jax.lax.all_gather(ins[0], axis, axis=1,
+                                                      tiled=True)
+                                   if dist else ins[0])
+            elif n.op == "reduce_scatter":
+                if dist and hier:
+                    out = jax.lax.psum_scatter(ins[0], axis[0],
+                                               scatter_dimension=1, tiled=True)
+                    env[n.name] = jax.lax.psum_scatter(out, axis[-1],
+                                                       scatter_dimension=1,
+                                                       tiled=True)
+                else:
+                    env[n.name] = (jax.lax.psum_scatter(ins[0], axis,
+                                                        scatter_dimension=1,
+                                                        tiled=True)
+                                   if dist else ins[0])
+            elif n.op == "allreduce":
+                env[n.name] = jax.lax.psum(ins[0], axis) if dist else ins[0]
+            elif n.op == "layernorm":
+                env[n.name] = apply_norm(norm, {"scale": ws[0]}, ins[0])
+            elif n.op in ("add", "residual"):
+                env[n.name] = ins[0] + ins[1]
+            elif n.op in _FN_OPS:
+                res = n.fn(*ins, *ws)
+                if len(n.outputs) > 1:
+                    for name, val in zip(n.outputs, res):
+                        env[name] = val
+                else:
+                    env[n.outputs[0]] = res
+            elif n.op == "a2a_ffn":
+                fn = (lambda chunk, _n=n, _ws=tuple(ws): _n.fn(chunk, *_ws))
+                env[n.name] = (be.a2a_expert_ffn(ins[0], fn, axis, cais)
+                               if dist else jax.vmap(fn)(ins[0]))
+            elif n.op == "ag_gemm":
+                env[n.name] = (be.ag_gemm(ins[0], ws[0], axis, cais)
+                               if dist else ins[0] @ ws[0])
+            elif n.op == "ag_gemm_multi":
+                outs = (be.ag_gemm_multi(ins[0], tuple(ws), axis, cais)
+                        if dist else tuple(ins[0] @ w for w in ws))
+                for name, val in zip(n.outputs, outs):
+                    env[name] = val
+            elif n.op == "gemm_rs":
+                env[n.name] = (be.gemm_rs(ins[0], ws[0], axis, cais)
+                               if dist else ins[0] @ ws[0])
+            elif n.op == "bwd_ag_gemm":
+                # adjoint of gemm_rs: gather the seq-sharded cotangent, GEMM
+                # with the transposed weight; the gathered cotangent is
+                # re-exposed for the weight-gradient GEMM (outputs (d_x,
+                # dy_full))
+                dx_, dyf = (be.grad_ag_gemm(ins[0], ws[0], axis, cais)
+                            if dist else (ins[0] @ ws[0], ins[0]))
+                env[n.outputs[0]], env[n.outputs[1]] = dx_, dyf
+            elif n.op == "bwd_a2a_ffn":
+                # adjoint of a2a_ffn: re-dispatch (send-row, cotangent-row)
+                # pairs to the expert owners, per-row VJP of the expert fn
+                # there, return d(recv) to the senders; the owner keeps its
+                # local expert-weight grads. outputs = (d_send, dw...)
+                def _row_vjp(chunk, gyc, _n=n, _ws=tuple(ws)):
+                    _, pull = jax.vjp(lambda c, *w: _n.fn(c, *w), chunk, *_ws)
+                    gr = pull(gyc)
+                    return gr[0], tuple(gr[1:])
+                if dist:
+                    dsend, dws_ = be.grad_a2a_expert_ffn(ins[0], ins[1],
+                                                         _row_vjp, axis, cais)
+                else:
+                    d_rows, dw_rows = jax.vmap(_row_vjp)(ins[0], ins[1])
+                    dsend = d_rows
+                    dws_ = tuple(jnp.sum(a, axis=0) for a in dw_rows)
+                for name, val in zip(n.outputs, (dsend,) + tuple(dws_)):
+                    env[name] = val
+            elif n.op == "gemm_ar":
+                env[n.name] = (be.gemm_ar(ins[0], ws[0], axis, cais)
+                               if dist else ins[0] @ ws[0])
+            elif n.op == "fused_rs_ln_ag":
+                w1, scale, w2 = ws
+                res = env[n.inputs[1]] if len(n.inputs) > 1 else None
+                if dist:
+                    out, z = be.fused_rs_ln_ag(ins[0], w1, scale, w2, axis,
+                                               cais, norm=norm, residual=res)
+                else:
+                    z = ins[0] @ w1
+                    if res is not None:
+                        z = z + res
+                    out = apply_norm(norm, {"scale": scale}, z) @ w2
+                env[n.outputs[0]], env[n.outputs[1]] = out, z
+            elif n.op == "fused_rs_ln_ag_multi":
+                w1, scale = ws[0], ws[1]
+                ws2 = tuple(ws[2:])
+                res = env[n.inputs[1]] if len(n.inputs) > 1 else None
+                if dist:
+                    outs, z = be.fused_rs_ln_ag_multi(ins[0], w1, scale, ws2,
+                                                      axis, cais, norm=norm,
+                                                      residual=res)
+                else:
+                    z = ins[0] @ w1
+                    if res is not None:
+                        z = z + res
+                    zn = apply_norm(norm, {"scale": scale}, z)
+                    outs = tuple(zn @ w for w in ws2)
+                for name, val in zip(n.outputs, outs + (z,)):
+                    env[name] = val
+            elif n.op == "fused_rs_ln":
+                w1, scale = ws
+                res = env[n.inputs[1]] if len(n.inputs) > 1 else None
+                if dist:
+                    zn, z = be.fused_rs_ln(ins[0], w1, scale, axis, cais,
+                                           norm=norm, residual=res)
+                else:
+                    z = ins[0] @ w1
+                    if res is not None:
+                        z = z + res
+                    zn = apply_norm(norm, {"scale": scale}, z)
+                env[n.outputs[0]], env[n.outputs[1]] = zn, z
+            elif n.op == "overlap_asym":
+                w_rs = ws[0]
+                ag_ws = tuple(ws[1:])
+                w_ag = ag_ws if len(ag_ws) > 1 else ag_ws[0]
+                if dist:
+                    rs_out, ag_out = be.overlap_asymmetric(
+                        (ins[0], w_rs), (ins[1], w_ag), axis, cais)
+                else:
+                    rs_out = ins[0] @ w_rs
+                    ag_out = (tuple(ins[1] @ w for w in ag_ws)
+                              if len(ag_ws) > 1 else ins[1] @ ag_ws[0])
+                ag_outs = ag_out if isinstance(ag_out, tuple) else (ag_out,)
+                for name, val in zip(n.outputs, (rs_out,) + ag_outs):
                     env[name] = val
             else:
-                env[n.outputs[0]] = res
-        elif n.op == "a2a_ffn":
-            fn = (lambda chunk, _n=n, _ws=tuple(ws): _n.fn(chunk, *_ws))
-            env[n.name] = (be.a2a_expert_ffn(ins[0], fn, axis, cais)
-                           if dist else jax.vmap(fn)(ins[0]))
-        elif n.op == "ag_gemm":
-            env[n.name] = (be.ag_gemm(ins[0], ws[0], axis, cais)
-                           if dist else ins[0] @ ws[0])
-        elif n.op == "ag_gemm_multi":
-            outs = (be.ag_gemm_multi(ins[0], tuple(ws), axis, cais)
-                    if dist else tuple(ins[0] @ w for w in ws))
-            for name, val in zip(n.outputs, outs):
-                env[name] = val
-        elif n.op == "gemm_rs":
-            env[n.name] = (be.gemm_rs(ins[0], ws[0], axis, cais)
-                           if dist else ins[0] @ ws[0])
-        elif n.op == "bwd_ag_gemm":
-            # adjoint of gemm_rs: gather the seq-sharded cotangent, GEMM with
-            # the transposed weight; the gathered cotangent is re-exposed for
-            # the weight-gradient GEMM (outputs (d_x, dy_full))
-            dx_, dyf = (be.grad_ag_gemm(ins[0], ws[0], axis, cais)
-                        if dist else (ins[0] @ ws[0], ins[0]))
-            env[n.outputs[0]], env[n.outputs[1]] = dx_, dyf
-        elif n.op == "bwd_a2a_ffn":
-            # adjoint of a2a_ffn: re-dispatch (send-row, cotangent-row)
-            # pairs to the expert owners, per-row VJP of the expert fn
-            # there, return d(recv) to the senders; the owner keeps its
-            # local expert-weight grads. outputs = (d_send, dw...)
-            def _row_vjp(chunk, gyc, _n=n, _ws=tuple(ws)):
-                _, pull = jax.vjp(lambda c, *w: _n.fn(c, *w), chunk, *_ws)
-                gr = pull(gyc)
-                return gr[0], tuple(gr[1:])
-            if dist:
-                dsend, dws_ = be.grad_a2a_expert_ffn(ins[0], ins[1],
-                                                     _row_vjp, axis, cais)
-            else:
-                d_rows, dw_rows = jax.vmap(_row_vjp)(ins[0], ins[1])
-                dsend = d_rows
-                dws_ = tuple(jnp.sum(a, axis=0) for a in dw_rows)
-            for name, val in zip(n.outputs, (dsend,) + tuple(dws_)):
-                env[name] = val
-        elif n.op == "gemm_ar":
-            env[n.name] = (be.gemm_ar(ins[0], ws[0], axis, cais)
-                           if dist else ins[0] @ ws[0])
-        elif n.op == "fused_rs_ln_ag":
-            w1, scale, w2 = ws
-            res = env[n.inputs[1]] if len(n.inputs) > 1 else None
-            if dist:
-                out, z = be.fused_rs_ln_ag(ins[0], w1, scale, w2, axis,
-                                           cais, norm=norm, residual=res)
-            else:
-                z = ins[0] @ w1
-                if res is not None:
-                    z = z + res
-                out = apply_norm(norm, {"scale": scale}, z) @ w2
-            env[n.outputs[0]], env[n.outputs[1]] = out, z
-        elif n.op == "fused_rs_ln_ag_multi":
-            w1, scale = ws[0], ws[1]
-            ws2 = tuple(ws[2:])
-            res = env[n.inputs[1]] if len(n.inputs) > 1 else None
-            if dist:
-                outs, z = be.fused_rs_ln_ag_multi(ins[0], w1, scale, ws2,
-                                                  axis, cais, norm=norm,
-                                                  residual=res)
-            else:
-                z = ins[0] @ w1
-                if res is not None:
-                    z = z + res
-                zn = apply_norm(norm, {"scale": scale}, z)
-                outs = tuple(zn @ w for w in ws2)
-            for name, val in zip(n.outputs, outs + (z,)):
-                env[name] = val
-        elif n.op == "fused_rs_ln":
-            w1, scale = ws
-            res = env[n.inputs[1]] if len(n.inputs) > 1 else None
-            if dist:
-                zn, z = be.fused_rs_ln(ins[0], w1, scale, axis, cais,
-                                       norm=norm, residual=res)
-            else:
-                z = ins[0] @ w1
-                if res is not None:
-                    z = z + res
-                zn = apply_norm(norm, {"scale": scale}, z)
-            env[n.outputs[0]], env[n.outputs[1]] = zn, z
-        elif n.op == "overlap_asym":
-            w_rs = ws[0]
-            ag_ws = tuple(ws[1:])
-            w_ag = ag_ws if len(ag_ws) > 1 else ag_ws[0]
-            if dist:
-                rs_out, ag_out = be.overlap_asymmetric(
-                    (ins[0], w_rs), (ins[1], w_ag), axis, cais)
-            else:
-                rs_out = ins[0] @ w_rs
-                ag_out = (tuple(ins[1] @ w for w in ag_ws)
-                          if len(ag_ws) > 1 else ins[1] @ ag_ws[0])
-            ag_outs = ag_out if isinstance(ag_out, tuple) else (ag_out,)
-            for name, val in zip(n.outputs, (rs_out,) + ag_outs):
-                env[name] = val
-        else:
-            raise ValueError(n.op)
+                raise ValueError(n.op)
     return tuple(env[o] for o in g.outputs)
 
 

@@ -1006,30 +1006,32 @@ def sp_period(tpc: TPContext, x, params_seq, cfg, kinds: Sequence[str], *,
     n_aux = len(aux_vals)
 
     def local(x, *ws):
-        wmap = dict(zip(names, ws))
-        if mb == 1:
-            res = df.execute(graph, {"x": x}, wmap, axis=M,
-                             cais=tpc.cais, norm=norm_kind,
-                             backend=tpc.backend)
-            if n_aux:
-                # aux leaves the shard_map sharded over (batch, model): the
-                # per-shard statistics genuinely differ per data shard, so a
-                # replicated out-spec would be a lie (check_vma=False never
-                # verifies it) and its autodiff transpose ill-defined
-                res = tuple(res[:1]) + tuple(a[None] for a in res[1:])
-            return res
-        res = df.execute(
-            graph,
-            {f"mb{i}.x": xi
-             for i, xi in enumerate(jnp.split(x, mb, axis=0))},
-            wmap, axis=M, cais=tpc.cais, norm=norm_kind,
-            backend=tpc.backend)
-        per = 1 + n_aux
-        out = jnp.concatenate([res[i * per] for i in range(mb)], axis=0)
-        auxes = tuple(
-            (sum(res[i * per + 1 + j] for i in range(mb)) / mb)[None]
-            for j in range(n_aux))
-        return (out,) + auxes
+        with jax.named_scope("tp.period"):
+            wmap = dict(zip(names, ws))
+            if mb == 1:
+                res = df.execute(graph, {"x": x}, wmap, axis=M,
+                                 cais=tpc.cais, norm=norm_kind,
+                                 backend=tpc.backend)
+                if n_aux:
+                    # aux leaves the shard_map sharded over (batch, model):
+                    # the per-shard statistics genuinely differ per data
+                    # shard, so a replicated out-spec would be a lie
+                    # (check_vma=False never verifies it) and its autodiff
+                    # transpose ill-defined
+                    res = tuple(res[:1]) + tuple(a[None] for a in res[1:])
+                return res
+            res = df.execute(
+                graph,
+                {f"mb{i}.x": xi
+                 for i, xi in enumerate(jnp.split(x, mb, axis=0))},
+                wmap, axis=M, cais=tpc.cais, norm=norm_kind,
+                backend=tpc.backend)
+            per = 1 + n_aux
+            out = jnp.concatenate([res[i * per] for i in range(mb)], axis=0)
+            auxes = tuple(
+                (sum(res[i * per + 1 + j] for i in range(mb)) / mb)[None]
+                for j in range(n_aux))
+            return (out,) + auxes
 
     x_spec = (BATCH, M, None) if o.seq_sharded else (BATCH, None, None)
     in_specs = [x_spec] + [specs[k] for k in names]
@@ -1087,39 +1089,40 @@ def sp_period(tpc: TPContext, x, params_seq, cfg, kinds: Sequence[str], *,
         grad_psum_axes[k] = tuple(a for a in tp_names if a not in mentioned)
 
     def local_bwd(x, gy, *rest):
-        gauxes, ws = rest[:n_aux], rest[n_aux:]
-        wmap = df.derived_weights(bwd_graph, dict(zip(names, ws)))
-        vals = {}
-        xs = jnp.split(x, mb, axis=0) if mb > 1 else [x]
-        gys = jnp.split(gy, mb, axis=0) if mb > 1 else [gy]
-        vals.update(zip(chains, xs))
-        # cotangent seeds in graph-output order: per chain (d.out,
-        # d.aux...). The fwd reports the mean of per-chain aux values, so
-        # each chain's aux seed carries 1/mb of the aux cotangent; gauxes
-        # arrive (batch, model)-sharded, so ga[0] is exactly this device's
-        # slice of the aux cotangent — no replication ambiguity.
-        seeds = []
-        for i in range(mb):
-            seeds.append(gys[i])
-            seeds.extend(ga[0] / mb for ga in gauxes)
-        vals.update(zip(tg.grad_inputs, seeds))
-        res = df.execute(bwd_graph, vals, wmap, axis=M, cais=tpc.cais,
-                         norm=norm_kind, backend=tpc.backend)
-        got = dict(zip(bwd_graph.outputs, res))
-        dxs = [got[tg.dx[c]] for c in chains]
-        dx = jnp.concatenate(dxs, axis=0) if mb > 1 else dxs[0]
-        dws = []
-        for k, w in zip(names, ws):
-            parts = [got[v] for v in tg.dweights.get(k, ())]
-            dw = parts[0] if parts else jnp.zeros_like(w)
-            for p_ in parts[1:]:
-                dw = dw + p_
-            if batch_axes:
-                dw = jax.lax.psum(dw, batch_axes)
-            if grad_psum_axes[k]:
-                dw = jax.lax.psum(dw, grad_psum_axes[k])
-            dws.append(dw.astype(w.dtype))
-        return (dx.astype(x.dtype),) + tuple(dws)
+        with jax.named_scope("tp.period"):
+            gauxes, ws = rest[:n_aux], rest[n_aux:]
+            wmap = df.derived_weights(bwd_graph, dict(zip(names, ws)))
+            vals = {}
+            xs = jnp.split(x, mb, axis=0) if mb > 1 else [x]
+            gys = jnp.split(gy, mb, axis=0) if mb > 1 else [gy]
+            vals.update(zip(chains, xs))
+            # cotangent seeds in graph-output order: per chain (d.out,
+            # d.aux...). The fwd reports the mean of per-chain aux values, so
+            # each chain's aux seed carries 1/mb of the aux cotangent; gauxes
+            # arrive (batch, model)-sharded, so ga[0] is exactly this device's
+            # slice of the aux cotangent — no replication ambiguity.
+            seeds = []
+            for i in range(mb):
+                seeds.append(gys[i])
+                seeds.extend(ga[0] / mb for ga in gauxes)
+            vals.update(zip(tg.grad_inputs, seeds))
+            res = df.execute(bwd_graph, vals, wmap, axis=M, cais=tpc.cais,
+                             norm=norm_kind, backend=tpc.backend)
+            got = dict(zip(bwd_graph.outputs, res))
+            dxs = [got[tg.dx[c]] for c in chains]
+            dx = jnp.concatenate(dxs, axis=0) if mb > 1 else dxs[0]
+            dws = []
+            for k, w in zip(names, ws):
+                parts = [got[v] for v in tg.dweights.get(k, ())]
+                dw = parts[0] if parts else jnp.zeros_like(w)
+                for p_ in parts[1:]:
+                    dw = dw + p_
+                if batch_axes:
+                    dw = jax.lax.psum(dw, batch_axes)
+                if grad_psum_axes[k]:
+                    dw = jax.lax.psum(dw, grad_psum_axes[k])
+                dws.append(dw.astype(w.dtype))
+            return (dx.astype(x.dtype),) + tuple(dws)
 
     bwd_call = _smap(tpc, local_bwd,
                      [x_spec, x_spec] + [(BATCH, M)] * n_aux

@@ -629,8 +629,9 @@ class LM:
         return (params["embed"] if tied else params["lm_head"]), tied
 
     def _embed(self, params, tokens, dtype):
-        e = params["embed"].astype(dtype)[tokens]
-        return sharding.shard(e, sharding.BATCH_AXES, None, None)
+        with jax.named_scope("model.embed"):
+            e = params["embed"].astype(dtype)[tokens]
+            return sharding.shard(e, sharding.BATCH_AXES, None, None)
 
     # ----- training -----
     def forward(self, params, tokens):
@@ -638,7 +639,8 @@ class LM:
         dtype = self.rt.dtype
         x = self._embed(params, tokens, dtype)
         x, aux = stack_forward(params["stack"], x, self.cfg, self.rt)
-        x = apply_norm(self.cfg.norm, params["final_norm"], x)
+        with jax.named_scope("model.head"):
+            x = apply_norm(self.cfg.norm, params["final_norm"], x)
         return x, aux
 
     def loss(self, params, batch) -> jnp.ndarray:
@@ -646,7 +648,9 @@ class LM:
         mask = batch.get("mask", jnp.ones_like(labels, jnp.float32))
         x, aux = self.forward(params, tokens)
         head, tied = self._head(params)
-        ce = chunked_ce_loss(x, head, labels, mask, self.cfg, self.rt, tied)
+        with jax.named_scope("model.head"):
+            ce = chunked_ce_loss(x, head, labels, mask, self.cfg, self.rt,
+                                 tied)
         return ce + AUX_LOSS_WEIGHT * aux
 
     # ----- serving -----

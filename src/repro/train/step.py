@@ -72,8 +72,9 @@ def make_train_step(model, opt: Optimizer, rt: Runtime,
             loss = loss / microbatches
             grads = jax.tree.map(lambda g: g / microbatches, grads)
 
-        new_params, new_opt = opt.apply(params, grads, state["opt"],
-                                        state["step"])
+        with jax.named_scope("step.optimizer"):
+            new_params, new_opt = opt.apply(params, grads, state["opt"],
+                                            state["step"])
         metrics = {
             "loss": loss.astype(jnp.float32),
             "grad_norm": global_norm(grads),

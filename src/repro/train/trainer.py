@@ -92,11 +92,22 @@ class Trainer:
                     sharding.use_mesh(self.mesh):
                 start = int(state["step"])
                 for step in range(start, self.cfg.total_steps):
+                    # three host phases that do not overlap, each a
+                    # profiler span (recorded only while a trace runs) and
+                    # a time in the history: make the batch, dispatch the
+                    # step, wait for its loss
                     t0 = self._clock()
-                    batch = make_batch(self.arch, self.shape, step, self.data)
-                    state, metrics = self.train_step(state, batch)
-                    loss = float(metrics["loss"])
-                    dt = self._clock() - t0
+                    with jax.profiler.TraceAnnotation("train.batch"):
+                        batch = make_batch(self.arch, self.shape, step,
+                                           self.data)
+                    t1 = self._clock()
+                    with jax.profiler.TraceAnnotation("train.dispatch"):
+                        state, metrics = self.train_step(state, batch)
+                    t2 = self._clock()
+                    with jax.profiler.TraceAnnotation("train.sync"):
+                        loss = float(metrics["loss"])
+                    t3 = self._clock()
+                    dt = t3 - t0
 
                     if self.watchdog.observe(step, dt):
                         self.events.append(f"straggler@{step}")
@@ -107,7 +118,9 @@ class Trainer:
                         self.watchdog.reset()
 
                     self.history.append(
-                        {"step": step, "loss": loss, "dt": dt})
+                        {"step": step, "loss": loss, "dt": dt,
+                         "batch_s": t1 - t0, "dispatch_s": t2 - t1,
+                         "sync_s": t3 - t2})
                     if step % self.cfg.log_every == 0:
                         print(f"step {step:6d} loss {loss:8.4f} "
                               f"dt {dt*1e3:7.1f}ms")

@@ -178,13 +178,16 @@ def test_straggler_watchdog_detects_and_checkpoints(tmp_path):
     """Inject slow steps via the trainer clock; the watchdog must flag and
     drop a checkpoint for orchestrated restart."""
     cfg, model, opt = tiny_setup()
-    times = iter([0.0, 0.1,            # step0: 0.1s
-                  0.2, 0.3,            # step1: 0.1
-                  0.4, 0.5,            # step2: 0.1
-                  1.0, 2.0,            # step3: 1.0  (slow)
-                  3.0, 4.0,            # step4: 1.0  (slow)
-                  5.0, 6.0,            # step5: 1.0  (slow -> 3 strikes)
-                  7.0, 7.1, 7.2, 7.3])
+    steps = [(0.0, 0.1),              # step0: 0.1s
+             (0.2, 0.3),              # step1: 0.1
+             (0.4, 0.5),              # step2: 0.1
+             (1.0, 2.0),              # step3: 1.0  (slow)
+             (3.0, 4.0),              # step4: 1.0  (slow)
+             (5.0, 6.0),              # step5: 1.0  (slow -> 3 strikes)
+             (7.0, 7.1), (7.2, 7.3)]
+    # a step reads the clock at its start, after the batch, after the
+    # dispatch and at its end; dt is end minus start
+    times = iter([t for a, b in steps for t in (a, a, b, b)])
     tc = TrainerConfig(total_steps=7, ckpt_dir=str(tmp_path),
                        ckpt_every=1000, log_every=100,
                        straggler_threshold=2.0)
