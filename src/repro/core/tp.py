@@ -429,13 +429,35 @@ def sp_ffn(tpc: TPContext, x, norm_scale, w_up, w_gate, w_down,
 # ---------------------------------------------------------------------------
 
 
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def _flash_applies(seq_len: int, dh: int, heads: int, kv_heads: int,
+                   window: int, prefix_len: int) -> bool:
+    """Whether a local attention core runs the Pallas causal flash kernel
+    (``kernels.ops.causal_flash_attention``): plain causal MHA on the TPU
+    with a sequence the kernel's block divides and a lane-aligned head
+    size. Everything else keeps the XLA core."""
+    from repro.kernels import ops
+
+    return (window == 0 and prefix_len == 0 and heads == kv_heads
+            and dh % ops.LANE == 0 and ops.flash_block(seq_len) is not None
+            and _on_tpu())
+
+
 def _attention_core_fn(cfg, tp: int, window: int = 0, prefix_len: int = 0,
                        axis=MODEL) -> Callable:
-    """The local attention math (rope, KV head slicing, flash core, head
+    """The local attention math (rope, KV head slicing, attention core, head
     reshape) as a closure for a ``custom`` IR node — shared by
     :func:`sp_attention` and :func:`sp_block`. ``axis`` is the TP axis entry
     (a name, or the composite 2D tuple — the replicated-KV slice uses the
-    flattened shard index, which matches contiguous head sharding)."""
+    flattened shard index, which matches contiguous head sharding).
+
+    The core is the Pallas flash kernel where :func:`_flash_applies` (under
+    the scope ``attn.flash``), else ``attention_core``'s chunked XLA scan
+    (under ``attn.xla``); both compute the same function."""
+    from repro.kernels import ops
     from repro.models.attention import attention_core
     from repro.models.layers import apply_rope
 
@@ -447,7 +469,9 @@ def _attention_core_fn(cfg, tp: int, window: int = 0, prefix_len: int = 0,
         H_loc = max(H // tp, 1)
         Hkv_loc = max(Hkv // tp, 1) if kv_sharded else Hkv
         pos = jnp.broadcast_to(jnp.arange(S), (B_, S))
-        q = apply_rope(q.reshape(B_, S, H_loc, dh), pos, cfg.rope_theta)
+        # rope in float32 either way; q keeps it until the core rounds it
+        q32 = apply_rope(q.reshape(B_, S, H_loc, dh).astype(jnp.float32),
+                         pos, cfg.rope_theta)
         k = apply_rope(k.reshape(B_, S, Hkv_loc, dh), pos, cfg.rope_theta)
         v = v.reshape(B_, S, Hkv_loc, dh)
         if not kv_sharded:
@@ -458,8 +482,15 @@ def _attention_core_fn(cfg, tp: int, window: int = 0, prefix_len: int = 0,
             start = (sharding.shard_map_axis_index(axis) * H_loc) // g
             k = jax.lax.dynamic_slice_in_dim(k, start, need, axis=2)
             v = jax.lax.dynamic_slice_in_dim(v, start, need, axis=2)
-        o = attention_core(q, k, v, q_positions=pos, kv_positions=pos,
-                           causal=True, window=window, prefix_len=prefix_len)
+        if _flash_applies(S, dh, H_loc, k.shape[2], window, prefix_len):
+            with jax.named_scope("attn.flash"):
+                o = ops.causal_flash_attention(q32, k, v)
+        else:
+            with jax.named_scope("attn.xla"):
+                o = attention_core(q32.astype(q.dtype), k, v,
+                                   q_positions=pos, kv_positions=pos,
+                                   causal=True, window=window,
+                                   prefix_len=prefix_len)
         return o.reshape(B_, S, H_loc * dh)
 
     return core
